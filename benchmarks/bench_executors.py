@@ -187,6 +187,8 @@ def bench_executors(fast: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, us, derived in bench_executors():
         print(f"{name},{us:.1f},{derived}")

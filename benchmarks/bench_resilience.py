@@ -232,6 +232,8 @@ def bench_resilience(fast: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     fast = "--fast" in sys.argv
     print("name,us_per_call,derived")
     for name, us, derived in bench_resilience(fast=fast):

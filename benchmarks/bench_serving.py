@@ -195,6 +195,8 @@ def bench_serving(fast: bool = False, json_path: str = JSON_PATH) -> List[Row]:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     fast = "--fast" in sys.argv
     print("name,us_per_call,derived")
     for name, us, derived in bench_serving(fast=fast):

@@ -37,6 +37,7 @@ def main() -> None:
         ("Roofline (from dry-run)", bench_roofline),
     ]
     print("name,us_per_call,derived")
+    failed = []
     for title, fn in sections:
         print(f"# --- {title} ---", file=sys.stderr)
         try:
@@ -44,7 +45,12 @@ def main() -> None:
                 print(f"{name},{us:.1f},{derived}")
         except Exception as e:  # noqa: BLE001
             print(f"{title}_ERROR,0,{type(e).__name__}: {e}")
+            failed.append(title)
+    if failed:
+        sys.exit(f"{len(failed)} section(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

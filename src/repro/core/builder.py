@@ -39,6 +39,7 @@ import difflib
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core as jax_core
 import jax.numpy as jnp
 
 from repro.core.actor import ActorSpec
@@ -78,19 +79,19 @@ def _canonical_enable_str(closed) -> str:
     import numpy as np
     jaxpr = closed.jaxpr
     needed = {id(v) for v in jaxpr.outvars
-              if not isinstance(v, jax.core.Literal)}
+              if not isinstance(v, jax_core.Literal)}
     kept = []
     for eqn in reversed(jaxpr.eqns):
         if any(id(v) in needed for v in eqn.outvars):
             kept.append(eqn)
             needed |= {id(v) for v in eqn.invars
-                       if not isinstance(v, jax.core.Literal)}
+                       if not isinstance(v, jax_core.Literal)}
     kept.reverse()
     consts = {id(v): c for v, c in zip(jaxpr.constvars, closed.consts)}
     names: Dict[int, str] = {}
 
     def nm(v) -> str:
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, jax_core.Literal):
             return f"lit({v.val!r}:{getattr(v.aval, 'dtype', None)})"
         if id(v) not in names:
             if id(v) in consts:
@@ -147,10 +148,10 @@ def _enable_expr(actor: ActorSpec, port: str,
     # Dataflow reachability: does any outvar depend on the token invar?
     reached = {id(v) for v in jaxpr.invars}
     for eqn in jaxpr.eqns:
-        if any(not isinstance(v, jax.core.Literal) and id(v) in reached
+        if any(not isinstance(v, jax_core.Literal) and id(v) in reached
                for v in eqn.invars):
             reached |= {id(v) for v in eqn.outvars}
-    depends = any(not isinstance(v, jax.core.Literal) and id(v) in reached
+    depends = any(not isinstance(v, jax_core.Literal) and id(v) in reached
                   for v in jaxpr.outvars)
     if not depends:
         try:
@@ -192,8 +193,8 @@ def _ports_provably_equal(actor: ActorSpec, p1: str, p2: str,
 
         closed = jax.make_jaxpr(f)(state0, ins)
         o1, o2 = closed.jaxpr.outvars
-        return (not isinstance(o1, jax.core.Literal)
-                and not isinstance(o2, jax.core.Literal)
+        return (not isinstance(o1, jax_core.Literal)
+                and not isinstance(o2, jax_core.Literal)
                 and o1 is o2)
     except Exception:
         return False

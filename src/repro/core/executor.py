@@ -101,6 +101,61 @@ def _is_concrete(x: Any) -> bool:
     return not isinstance(x, jax.core.Tracer)
 
 
+#: Arrays of at least this many bytes that an actor closes over (an LM's
+#: weights in the serving graph's decode actor) become arguments of the
+#: compiled runner; jit would otherwise embed them in the program as
+#: constants.
+_HOIST_CONST_BYTES = 1 << 20
+
+
+def _jit_runner(run: Callable, donate: bool) -> Callable:
+    """``jax.jit(run)`` for ``run(state)``, with large closed-over arrays
+    passed to the compiled program as (never donated) arguments.
+
+    The first call decides: a runner whose trace closes over no large
+    array is the plain jitted function from then on."""
+    jitted = jax.jit(run, donate_argnums=(0,) if donate else ())
+    hoisted: Dict[Any, Callable] = {}
+    plain = False
+
+    def call(state):
+        nonlocal plain
+        if plain:
+            return jitted(state)
+        traced = jitted.trace(state)       # reused by the call below
+        closed = traced.jaxpr
+        fn = hoisted.get(closed)
+        if fn is None:
+            big = [getattr(c, "nbytes", 0) >= _HOIST_CONST_BYTES
+                   for c in closed.consts]
+            if not hoisted and not any(big):
+                plain = True
+                return jitted(state)
+            fn = hoisted[closed] = _hoist(
+                closed, big, jax.tree.structure(traced.out_info), donate)
+        return fn(state)
+
+    return call
+
+
+def _hoist(closed, big: List[bool], out_tree, donate: bool) -> Callable:
+    consts = list(closed.consts)
+    args = [c for c, b in zip(consts, big) if b]
+
+    def flat_run(args, leaves):
+        it = iter(args)
+        cs = [next(it) if b else c for c, b in zip(consts, big)]
+        return jax.core.eval_jaxpr(closed.jaxpr, cs, *leaves)
+
+    jitted = jax.jit(flat_run, donate_argnums=(1,) if donate else ())
+
+    def call(state):
+        out = jitted(args, jax.tree.leaves(state))
+        return jax.tree.unflatten(out_tree, out)
+
+    return call
+
+
 # --------------------------------------------------------------------------- #
 # Single predicated firing (shared by all executors).
 # --------------------------------------------------------------------------- #
@@ -434,7 +489,7 @@ def _compile_static(network: Network, n_iterations: int,
             state = step(state, p if specialize else None)
         return state
 
-    jitted = jax.jit(run, donate_argnums=(0,) if donate else ())
+    jitted = _jit_runner(run, donate)
     if not specialize:
         return jitted
 
@@ -677,7 +732,7 @@ def _compile_dynamic(network: Network, max_sweeps: int = 1_000_000,
             return state, counts, sweeps, stalled, hlth, trc
         return state, counts
 
-    return jax.jit(run, donate_argnums=(0,) if donate else ())
+    return _jit_runner(run, donate)
 
 
 # --------------------------------------------------------------------------- #

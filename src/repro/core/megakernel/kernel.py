@@ -57,11 +57,11 @@ channels must enter **drained** (occupancy 0 — checked per run when
 cursors are concrete), else compile with ``specialize=False`` to keep
 every ring in scratch.
 
-**Interpret fallback.**  ``interpret=None`` auto-selects Pallas interpret
-mode off-TPU so tier-1 runs the kernel on CPU; the Mosaic (non-interpret)
-TPU path is a ROADMAP open item — actor bodies may use ops Mosaic cannot
-lower yet (MoE's top_k/scatter), so on TPU pass ``interpret=True`` to
-fall back deliberately.
+**Backends.**  On a TPU backend the kernel is compiled by Mosaic, and a
+body Mosaic cannot lower raises its lowering error; there is no interpret
+fallback on the chip.  On any other backend it runs in Pallas interpret
+mode (:func:`repro.kernels.resolve_interpret`), which is how the CPU
+tests run it.
 """
 from __future__ import annotations
 
@@ -84,6 +84,7 @@ from repro.core.megakernel.lower import (CURSOR_FIELDS, FiringRow,
                                          lower_network, partition_layout)
 from repro.core.network import Network, NetworkState
 from repro.core.trace import TraceState
+from repro.kernels import resolve_interpret
 
 # Cursor row layout inside each packed (rows, 3) cursor block.
 _RD, _WR, _OCC = 0, 1, 2
@@ -780,7 +781,6 @@ class _MegaResult(tuple):
 def compile_megakernel(network: Network, max_sweeps: int = 1_000_000,
                        mode: RuntimeMode = RuntimeMode.PROPOSED,
                        multi_firing: bool = True,
-                       interpret: Optional[bool] = None,
                        layout: Optional[MegakernelLayout] = None,
                        cores: int = 1,
                        assign: Optional[Dict[str, int]] = None,
@@ -814,9 +814,7 @@ def compile_megakernel(network: Network, max_sweeps: int = 1_000_000,
     every path (single-core, grid, forwarded windows).  The decoded
     :class:`repro.core.trace.TraceState` rides the result as ``.trace``.
 
-    ``interpret=None`` auto-selects Pallas interpret mode on non-TPU
-    backends (the tier-1 CPU fallback); pass an explicit bool to force
-    either path.  ``layout`` lets a caller that already lowered the
+    ``layout`` lets a caller that already lowered the
     network (``Program``) pass its :class:`MegakernelLayout` instead of
     lowering twice.
 
@@ -845,8 +843,6 @@ def compile_megakernel(network: Network, max_sweeps: int = 1_000_000,
                                      objective=cut_objective,
                                      forward_transients=forward_transients)
     fns, const_arrays = _hoist_consts(network, layout)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n_fifos = len(layout.fifo_specs)
     n_actors = len(network.actors)
     actor_names = tuple(network.actors)
@@ -902,7 +898,7 @@ def compile_megakernel(network: Network, max_sweeps: int = 1_000_000,
             kernel,
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
-            interpret=interpret,
+            interpret=resolve_interpret(None),
         )(*bufs, cursors, *kernel_leaves, *kernel_consts)
 
         bufs_o = outs[:n_fifos]

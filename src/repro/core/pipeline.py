@@ -18,10 +18,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -88,12 +84,9 @@ def pipeline_spmd(stage_fn: Callable[[Any, jax.Array], jax.Array],
         return outs
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    import inspect
-    kw = ("check_vma" if "check_vma" in
-          inspect.signature(shard_map).parameters else "check_rep")
-    fn = shard_map(per_shard, mesh=mesh,
-                   in_specs=(spec_params, P()), out_specs=P(),
-                   **{kw: False})
+    fn = jax.shard_map(per_shard, mesh=mesh,
+                       in_specs=(spec_params, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, microbatches)
 
 

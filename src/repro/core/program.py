@@ -145,9 +145,6 @@ class ExecutionPlan:
       order:         optional static firing order (defaults topological).
       max_sweeps:    dynamic/megakernel sweep bound.
       unroll_bound:  static mode phase-unroll period cap.
-      interpret:     megakernel mode: force Pallas interpret mode on
-                     (True) or off (False); ``None`` auto-selects
-                     interpret off-TPU (the tier-1 CPU fallback).
       cores:         megakernel mode: number of grid partitions for the
                      multi-core sweep (paper §3.3 actor-to-core
                      mapping).  Each core runs its own occupancy-bounded
@@ -245,7 +242,6 @@ class ExecutionPlan:
     order: Optional[Tuple[str, ...]] = None
     max_sweeps: int = 1_000_000
     unroll_bound: int = 6
-    interpret: Optional[bool] = None
     cores: int = 1
     assign: Optional[Mapping[str, int]] = None
     cut_objective: str = "crossing"
@@ -778,7 +774,7 @@ class Program:
             return compile_megakernel(
                 self.network, max_sweeps=plan.max_sweeps,
                 mode=plan.runtime_mode, multi_firing=plan.multi_firing,
-                interpret=plan.interpret, layout=self._layout,
+                layout=self._layout,
                 partition=self._partition, guards=plan.guards,
                 trace_capacity=trace_cap)
         return functools.partial(

@@ -64,7 +64,6 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.executor import (RuntimeMode, _make_visit_body,
@@ -285,8 +284,8 @@ def compile_sharded(network: Network, layout: MegakernelLayout,
                    jax.lax.all_gather(trc.count, AXIS))
         return state, counts, sweeps, stalled, hlth, trc
 
-    sharded = jax.jit(shard_map(sharded_run, mesh=mesh, in_specs=(P(),),
-                                out_specs=P(), check_rep=False))
+    sharded = jax.jit(jax.shard_map(sharded_run, mesh=mesh, in_specs=(P(),),
+                                    out_specs=P(), check_vma=False))
 
     def run(state):
         if not isinstance(state, NetworkState):

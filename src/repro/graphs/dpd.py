@@ -62,6 +62,47 @@ def default_active_schedule(n_firings: int, seed: int = 0,
     return np.repeat(per, RECONF_PERIOD_FIRINGS)[:n_firings].astype(np.int32)
 
 
+def branch_taps(k: int) -> np.ndarray:
+    """Poly branch ``k``'s (2, N_TAPS) f32 complex taps (re, im), fixed
+    per branch so runs and the oracle agree."""
+    return np.random.default_rng(100 + k).normal(
+        scale=0.3, size=(2, N_TAPS)).astype(np.float32)
+
+
+def dpd_oracle(signal: np.ndarray, active_schedule: np.ndarray,
+               block_l: int = BLOCK_L) -> np.ndarray:
+    """Plain NumPy float32 reference of the network's sink output.
+
+    ``signal``: (2, n_firings * block_l) re/im planes; firing ``f`` sums
+    branches ``k < active_schedule[f]``, each ``x * |x|^(2k)`` through
+    its 10-tap complex FIR with a 9-sample history that advances only
+    when the branch runs.
+    """
+    L = block_l
+    sig = np.asarray(signal, np.float32)
+    hist = [np.zeros((2, N_TAPS - 1), np.float32) for _ in range(N_BRANCHES)]
+    out = np.zeros_like(sig)
+    for f, n_active in enumerate(np.asarray(active_schedule)):
+        win = sig[:, f * L:(f + 1) * L]
+        for k in range(int(n_active)):
+            x = np.concatenate([hist[k], win], axis=1)
+            hist[k] = x[:, -(N_TAPS - 1):]
+            xr, xi = x
+            scale = (xr * xr + xi * xi) ** k
+            br, bi = xr * scale, xi * scale
+            hr, hi = branch_taps(k)
+            yr = np.zeros(L, np.float32)
+            yi = np.zeros(L, np.float32)
+            for t in range(N_TAPS):
+                sr = br[N_TAPS - 1 - t:N_TAPS - 1 - t + L]
+                si = bi[N_TAPS - 1 - t:N_TAPS - 1 - t + L]
+                yr = yr + hr[t] * sr - hi[t] * si
+                yi = yi + hr[t] * si + hi[t] * sr
+            out[0, f * L:(f + 1) * L] += yr
+            out[1, f * L:(f + 1) * L] += yi
+    return out
+
+
 def build_dpd(n_firings: int,
               active_schedule: Optional[np.ndarray] = None,
               block_l: int = BLOCK_L,
@@ -152,10 +193,7 @@ def build_dpd(n_firings: int,
 
         def init():
             hist = jnp.zeros((2, N_TAPS - 1), jnp.float32)
-            # Deterministic per-branch taps (benchmark/repro friendly).
-            rng = np.random.default_rng(100 + k)
-            taps = jnp.asarray(rng.normal(scale=0.3, size=(2, N_TAPS)), jnp.float32)
-            return (hist, taps)
+            return (hist, jnp.asarray(branch_taps(k)))
 
         def fire(state, inputs, rates):
             hist, taps = state

@@ -116,6 +116,47 @@ def build_motion_detection(n_frames: int, rate: int = 1,
     return b.build()
 
 
+def md_oracle(video: np.ndarray,
+              threshold: float = DEFAULT_THRESHOLD) -> np.ndarray:
+    """Plain NumPy reference of the network's sink output.
+
+    ``video``: (n_frames, H, W) values in [0, 255], rounded to u8 as the
+    source does.  Per frame: 25-tap binomial Gauss (2-pixel border passed
+    through), rounded to u8; ``|g_t - g_{t-1}| > threshold`` -> 255 else
+    0 against the previous filtered frame (zeros before the first, the
+    delay token); plus-shaped 5-point median with edge padding.  Every
+    partial sum is a multiple of 1/256 below 2^16, so the result is exact
+    in float32 whatever the summation order.
+    """
+    def u8(x):
+        return np.clip(np.round(x), 0, 255).astype(np.uint8)
+
+    vid = u8(np.asarray(video)).astype(np.float32)
+    n, H, W = vid.shape
+    w1 = np.array([1.0, 4.0, 6.0, 4.0, 1.0], np.float32) / 16
+    w2 = np.outer(w1, w1)
+    border = np.zeros((H, W), bool)
+    border[:2] = border[-2:] = True
+    border[:, :2] = border[:, -2:] = True
+    out = np.zeros((n, H, W), np.uint8)
+    prev = np.zeros((H, W), np.float32)
+    for i in range(n):
+        pad = np.pad(vid[i], 2, mode="edge")
+        acc = np.zeros((H, W), np.float32)
+        for dy in range(5):
+            for dx in range(5):
+                acc += w2[dy, dx] * pad[dy:dy + H, dx:dx + W]
+        g = u8(np.where(border, vid[i], acc)).astype(np.float32)
+        m = np.where(np.abs(g - prev) > threshold, 255.0, 0.0)
+        prev = g
+        p = np.pad(m, 1, mode="edge")
+        taps = np.stack([p[0:H, 1:W + 1], p[2:H + 2, 1:W + 1],
+                         p[1:H + 1, 0:W], p[1:H + 1, 2:W + 2],
+                         p[1:H + 1, 1:W + 1]])
+        out[i] = u8(np.median(taps, axis=0))
+    return out
+
+
 def bench_workload(n_frames: int, rate: int = 4,
                    frame_hw: Tuple[int, int] = (FRAME_H, FRAME_W),
                    seed: int = 0, **build_kw) -> Network:

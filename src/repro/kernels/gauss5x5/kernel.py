@@ -25,6 +25,10 @@ from repro.kernels.gauss5x5.ref import KERNEL_1D
 
 _W1D = [float(w) for w in KERNEL_1D]
 
+#: Output rows per grid step: a multiple of the (8, 128) tile's 8 rows
+#: that divides QVGA's 240.
+BLOCK_H = 48
+
 
 def _gauss_kernel(x_ref, o_ref, *, block_h: int, H: int):
     """One grid step: filter ``block_h`` output rows from the padded frame."""
@@ -52,7 +56,7 @@ def _gauss_kernel(x_ref, o_ref, *, block_h: int, H: int):
     o_ref[...] = jnp.where(border, centre, h)
 
 
-def gauss5x5_pallas(frame: jax.Array, *, block_h: int = 60,
+def gauss5x5_pallas(frame: jax.Array, *, block_h: int = BLOCK_H,
                     interpret: bool = False) -> jax.Array:
     """frame: (H, W) f32 in [0,255]. H must be divisible by block_h."""
     H, W = frame.shape

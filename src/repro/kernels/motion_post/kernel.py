@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.gauss5x5.kernel import BLOCK_H
 from repro.kernels.motion_post.ref import DEFAULT_THRESHOLD, median5
 
 
@@ -43,7 +44,7 @@ def _motion_post_kernel(cur_ref, prev_ref, o_ref, *, block_h: int, H: int,
 
 def motion_post_pallas(cur: jax.Array, prev: jax.Array, *,
                        threshold: float = DEFAULT_THRESHOLD,
-                       block_h: int = 60, interpret: bool = False) -> jax.Array:
+                       block_h: int = BLOCK_H, interpret: bool = False) -> jax.Array:
     """cur/prev: (H, W) f32. Fused thres+median motion map."""
     H, W = cur.shape
     if H % block_h:

@@ -6,6 +6,14 @@ touches jax device state — the dry-run sets XLA_FLAGS before first init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """Mesh with Auto axes: the model places activations with
+    ``with_sharding_constraint``, which Explicit axes (the
+    ``jax.make_mesh`` default) reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,9 +23,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     only DCN-crossing collective, by construction)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for subprocess-based distribution tests."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)

@@ -17,6 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, smoke_config
 from repro.data import DataConfig, SyntheticLM
+from repro.launch.mesh import make_test_mesh
 from repro.models import init_params
 from repro.optim.adamw import AdamWConfig, init_opt_state
 from repro.train import (Trainer, TrainerConfig, TrainOptions,
@@ -44,7 +45,7 @@ def main():
         shape = (n_dev, 1)
     else:
         shape = (16, 16)
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_test_mesh(shape)
     print(f"mesh: {dict(mesh.shape)}  arch: {cfg.name} "
           f"({cfg.param_count()/1e6:.1f}M params)")
 

@@ -149,3 +149,48 @@ def test_heterogeneous_split():
     got = np.asarray(collect_sink(sub, out_state, "__fetch_f2"))
     np.testing.assert_allclose(got.reshape(-1, 3),
                                2 * np.asarray(data).reshape(-1, 3))
+
+
+@pytest.mark.parametrize("mode", ["static", "dynamic"])
+def test_large_closed_over_array_is_a_runner_argument(monkeypatch, mode):
+    """An actor closing over a >= 1 MiB array (an LM's weights in the
+    serving graph) runs with the array passed to the compiled program as
+    an argument, not embedded as a constant; results are unchanged."""
+    import repro.core.executor as ex
+    hoisted = []
+    real = ex._hoist
+    monkeypatch.setattr(ex, "_hoist",
+                        lambda closed, big, *a: hoisted.append(sum(big))
+                        or real(closed, big, *a))
+    n, d = 4, 512
+    w = jax.random.normal(jax.random.PRNGKey(0), (d, d), jnp.float32)
+    xs = jax.random.normal(jax.random.PRNGKey(1), (n, d), jnp.float32)
+
+    def src_fire(state, inputs, rates):
+        return state + 1, {"out": jax.lax.dynamic_slice_in_dim(xs, state, 1)}
+
+    src = static_actor("src", (), ("out",), src_fire, init=lambda: jnp.int32(0),
+                       ready=lambda st: st < n)
+    mm = static_actor("mm", ("in",), ("out",),
+                      map_fire(lambda t: t @ w, "in", "out"))
+
+    def sink_fire(state, inputs, rates):
+        data, idx = state
+        return (jax.lax.dynamic_update_slice_in_dim(data, inputs["in"], idx, 0),
+                idx + 1), {}
+
+    snk = static_actor("snk", ("in",), (), sink_fire,
+                       init=lambda: (jnp.zeros((n, d), jnp.float32),
+                                     jnp.int32(0)),
+                       finish=lambda st: st[0])
+    b = NetworkBuilder()
+    b.actors(src, mm, snk)
+    b.connect("src.out", "mm.in", token_shape=(d,), name="f1")
+    b.connect("mm.out", "snk.in", token_shape=(d,), name="f2")
+    prog = b.build().compile(ExecutionPlan(
+        mode=mode, n_iterations=n if mode == "static" else None))
+    for _ in range(2):
+        got = np.asarray(prog.collect("snk", prog.run().state))
+        np.testing.assert_allclose(got, np.asarray(xs) @ np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+    assert hoisted == [1]

@@ -2,13 +2,9 @@
 XLA_FLAGS=--xla_force_host_platform_device_count=8 (the dry-run pattern;
 the main test process keeps its single CPU device)."""
 import os
-import re
 import subprocess
 import sys
 import textwrap
-
-import jax
-import pytest
 
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -61,8 +57,9 @@ def test_train_step_pjit_small_mesh():
         from repro.train import sharding as shd
         from repro.data import DataConfig, SyntheticLM
 
+        from repro.launch.mesh import make_test_mesh
         cfg = smoke_config("granite-8b")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_test_mesh((4, 2))
         key = jax.random.PRNGKey(0)
         params = init_params(key, cfg)
         opt = init_opt_state(params)
@@ -88,13 +85,6 @@ def test_train_step_pjit_small_mesh():
     """)
 
 
-@pytest.mark.skipif(
-    # Leading-digit parse so pre-release strings ("0.5.0rc0") compare.
-    tuple(int(re.match(r"\d*", p).group() or 0)
-          for p in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="compiled.cost_analysis() returns a per-module LIST in jax "
-           "0.4.37 (dryrun.py expects the dict of later releases) — "
-           f"pre-existing version drift, running {jax.__version__}")
 def test_dryrun_cell_mini_mesh():
     """The dry-run machinery end-to-end on an 8-chip (4 data x 2 model)
     mini-mesh: lower+compile+cost+collectives for one arch x shape."""
@@ -104,7 +94,8 @@ def test_dryrun_cell_mini_mesh():
         import repro.launch.dryrun as dr
         from repro.configs import smoke_config
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_test_mesh
+        mesh = make_test_mesh((4, 2))
         cfg = smoke_config("granite-8b")
         import repro.configs.base as base
         # shrink the global shape table for the mini run

@@ -5,23 +5,8 @@ import numpy as np
 import pytest
 
 from repro.core import ExecutionPlan, RuntimeMode, assert_mode_allows
-from repro.graphs.dpd import build_dpd
-from repro.graphs.motion_detection import build_motion_detection
-from repro.kernels.dyn_fir import N_TAPS, branch_ref
-from repro.kernels.gauss5x5 import gauss5x5
-from repro.kernels.motion_post import med_ref, thres_ref
-
-
-def _md_oracle(video_np):
-    u8 = lambda x: np.clip(np.round(x), 0, 255).astype(np.uint8)
-    NF, H, W = video_np.shape
-    vid = u8(video_np).astype(np.float32)
-    g = np.stack([u8(np.asarray(gauss5x5(jnp.asarray(v), impl="xla")))
-                  .astype(np.float32) for v in vid])
-    prev = np.concatenate([np.zeros((1, H, W), np.float32), g[:-1]])
-    return np.stack([u8(np.asarray(med_ref(thres_ref(jnp.asarray(g[i]),
-                                                     jnp.asarray(prev[i])))))
-                     for i in range(NF)])
+from repro.graphs.dpd import build_dpd, dpd_oracle
+from repro.graphs.motion_detection import build_motion_detection, md_oracle
 
 
 @pytest.mark.parametrize("rate", [1, 4])
@@ -33,7 +18,7 @@ def test_motion_detection_matches_oracle(rng, rate):
     prog = net.compile(mode="static", n_iterations=NF // rate)
     prog.run()
     np.testing.assert_allclose(np.asarray(prog.collect("sink")),
-                               _md_oracle(video))
+                               md_oracle(video))
 
 
 def test_motion_detection_buffer_memory_table1():
@@ -46,27 +31,6 @@ def test_dpd_buffer_memory_table1():
     assert abs(build_dpd(4).buffer_bytes() / 1e6 - 11.53) < 0.1  # paper: 11.5
 
 
-def _dpd_oracle(sig_np, sched, L):
-    taps = [np.random.default_rng(100 + k).normal(scale=0.3, size=(2, N_TAPS))
-            .astype(np.float32) for k in range(10)]
-    hist = [np.zeros((2, N_TAPS - 1), np.float32) for _ in range(10)]
-    out = np.zeros_like(sig_np)
-    for f in range(len(sched)):
-        win = sig_np[:, f * L:(f + 1) * L]
-        acc = np.zeros((2, L), np.float32)
-        for k in range(10):
-            if k < sched[f]:
-                xin = np.concatenate([hist[k], win], axis=1)
-                yr, yi = branch_ref(jnp.asarray(xin[0]), jnp.asarray(xin[1]),
-                                    jnp.asarray(taps[k][0]), jnp.asarray(taps[k][1]),
-                                    k + 1)
-                acc[0] += np.asarray(yr)
-                acc[1] += np.asarray(yi)
-                hist[k] = xin[:, -(N_TAPS - 1):]
-        out[:, f * L:(f + 1) * L] = acc
-    return out
-
-
 def test_dpd_dynamic_rates_match_oracle(rng):
     NF, L = 4, 1024
     sig = rng.normal(size=(2, NF * L)).astype(np.float32)
@@ -76,13 +40,13 @@ def test_dpd_dynamic_rates_match_oracle(rng):
     prog = net.compile(mode="static", n_iterations=NF)
     prog.run()
     got = np.asarray(prog.collect("sink"))
-    np.testing.assert_allclose(got, _dpd_oracle(sig, sched, L),
+    np.testing.assert_allclose(got, dpd_oracle(sig, sched, L),
                                rtol=6e-4, atol=6e-4)
     # token-driven scheduler agrees
     dyn = net.compile(ExecutionPlan(mode="dynamic"))
     result = dyn.run()
     np.testing.assert_allclose(np.asarray(dyn.collect("sink")),
-                               _dpd_oracle(sig, sched, L), rtol=6e-4, atol=6e-4)
+                               dpd_oracle(sig, sched, L), rtol=6e-4, atol=6e-4)
     assert int(result.fire_counts["config"]) == NF
 
 

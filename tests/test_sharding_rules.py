@@ -1,6 +1,4 @@
 """Sharding rule unit tests (no devices needed — specs only)."""
-import re
-
 import jax
 import jax.numpy as jnp
 import pytest
@@ -9,20 +7,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
 from repro.models import abstract_params
 from repro.train import sharding as shd
-
-# This module builds its abstract device grid with the
-# ``AbstractMesh(axis_sizes, axis_names)`` constructor, which landed
-# after jax 0.4.37 (0.4.37's AbstractMesh takes ``(name, size)`` pair
-# tuples instead, and the mesh fixture errors on construction).  The
-# pinned dev/CI environment is 0.4.37, so these 6 tests are skipped
-# there — the version-sensitive drift formerly handled with a CI
-# ``--ignore`` flag, now self-describing in the file itself.
-# Leading-digit parse so pre-release strings ("0.5.0rc0") still compare.
-pytestmark = pytest.mark.skipif(
-    tuple(int(re.match(r"\d*", p).group() or 0)
-          for p in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="AbstractMesh(axis_sizes, axis_names) constructor needs "
-           f"jax >= 0.5 (running {jax.__version__})")
 
 
 @pytest.fixture(scope="module")
