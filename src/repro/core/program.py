@@ -30,7 +30,6 @@ from __future__ import annotations
 import dataclasses
 import enum
 import functools
-import time
 import warnings
 from typing import Any, Dict, List, Mapping, Optional, Tuple, Union
 
@@ -48,8 +47,10 @@ from repro.core.health import (Diagnostics, NetworkFaultError, decode_health)
 from repro.core.mapping import heterogeneous_split
 from repro.core.network import (Network, NetworkState, iteration_token_flops)
 from repro.core.schedule import phase_unroll_period
-from repro.core.trace import (TRACE_CAPACITY_DEFAULT, Trace, decode_trace,
-                              merge_traces)
+from repro.core.trace import (SPAN_COLLECT, SPAN_DECODE, SPAN_LAUNCH,
+                              SPAN_PREPARE, SPAN_STAGE, SPAN_STREAM,
+                              SPAN_WAIT, TRACE_CAPACITY_DEFAULT, Trace,
+                              decode_trace, merge_traces)
 
 
 class Mode(str, enum.Enum):
@@ -628,8 +629,6 @@ def _trace_to_payload(t: Trace) -> Dict[str, Any]:
             "events": np.asarray(t.events, np.int32),
             "capacity": int(t.capacity),
             "dropped": int(t.dropped),
-            "wall_time_s": (None if t.wall_time_s is None
-                            else float(t.wall_time_s)),
             "actor_flops": [int(x) for x in t.actor_flops],
             "fifo_token_bytes": [int(x) for x in t.fifo_token_bytes],
             "actor_cores": (None if t.actor_cores is None
@@ -637,12 +636,12 @@ def _trace_to_payload(t: Trace) -> Dict[str, Any]:
 
 
 def _trace_from_payload(d: Mapping[str, Any]) -> Trace:
+    # Older snapshots also carry a "wall_time_s" entry; it is ignored.
     return Trace(actor_names=tuple(d["actor_names"]),
                  fifo_names=tuple(d["fifo_names"]),
                  events=np.asarray(d["events"], np.int32),
                  capacity=int(d["capacity"]),
                  dropped=int(d["dropped"]),
-                 wall_time_s=d["wall_time_s"],
                  actor_flops=tuple(int(x) for x in d["actor_flops"]),
                  fifo_token_bytes=tuple(int(x)
                                         for x in d["fifo_token_bytes"]),
@@ -849,45 +848,22 @@ class Program:
             donate_now = self.plan.donate is True
         runner = self._runners[donate_now]
         if self.plan.mode in ("dynamic", "megakernel"):
-            t0 = time.perf_counter() if self.plan.trace else None
-            if self.plan.mode == "dynamic":
-                final, counts, sweeps, stalled, health, trc = runner(st)
-            else:
-                res = runner(st)     # _MegaResult: 3-tuple + attributes
-                final, counts, sweeps = res
-                stalled, health = res.stalled, res.health
-                trc = res.trace
+            with jax.profiler.TraceAnnotation(SPAN_LAUNCH):
+                if self.plan.mode == "dynamic":
+                    final, counts, sweeps, stalled, health, trc = runner(st)
+                else:
+                    res = runner(st)     # _MegaResult: 3-tuple + attributes
+                    final, counts, sweeps = res
+                    stalled, health = res.stalled, res.health
+                    trc = res.trace
             # One scalar host sync; a stalled exit then pays the eager
             # per-actor forensics, the path where latency is moot.
-            stalled_b = bool(stalled)
-            trace = None
-            if trc is not None:
-                # The bool() sync above blocked until the computation
-                # finished, so this wall-clock covers the whole run —
-                # the per-firing cost attribution is proportional, not a
-                # per-event clock (none exists inside one jitted sweep
-                # loop).
-                dt = time.perf_counter() - t0
-                if self.plan.devices > 1:
-                    # Sharded runs return one per-device trace ring each
-                    # (all-gathered); decode and merge, with actor_cores
-                    # recording the mesh device instead of a grid core.
-                    from repro.core.shard import decode_device_trace
-                    trace = decode_device_trace(
-                        self.network, trc, self._shard_partition,
-                        wall_time_s=dt)
-                else:
-                    cores = None
-                    part = self._partition
-                    if part is not None and part.n_cores > 1:
-                        names = tuple(self.network.actors)
-                        cores = {names[i]: c
-                                 for c, rows in enumerate(part.core_rows)
-                                 for i in rows}
-                    trace = decode_trace(self.network, trc, wall_time_s=dt,
-                                         actor_cores=cores)
-            diag = decode_health(self.network, health, stalled_b,
-                                 final if stalled_b else None)
+            with jax.profiler.TraceAnnotation(SPAN_WAIT):
+                stalled_b = bool(stalled)
+            with jax.profiler.TraceAnnotation(SPAN_DECODE):
+                trace = None if trc is None else self._decode_ring(trc)
+                diag = decode_health(self.network, health, stalled_b,
+                                     final if stalled_b else None)
             result = RunResult(final, fire_counts=counts, sweeps=sweeps,
                                diagnostics=diag, trace=trace)
             self._last = result
@@ -907,10 +883,29 @@ class Program:
                         f"with work remaining — partial state returned; "
                         f"{diag.summary()}", RuntimeWarning, stacklevel=2)
             return result
-        result = RunResult(runner(st))  # static/interpreted: bare state
+        with jax.profiler.TraceAnnotation(SPAN_LAUNCH):
+            final = runner(st)           # static/interpreted: bare state
+        result = RunResult(final)
         self._last = result
         self._last_is_stream_chunk = False
         return result
+
+    def _decode_ring(self, trc) -> Trace:
+        """The run's device trace ring as a host :class:`Trace`."""
+        if self.plan.devices > 1:
+            # Sharded runs return one per-device trace ring each
+            # (all-gathered); decode and merge, with actor_cores recording
+            # the mesh device instead of a grid core.
+            from repro.core.shard import decode_device_trace
+            return decode_device_trace(self.network, trc,
+                                       self._shard_partition)
+        cores = None
+        part = self._partition
+        if part is not None and part.n_cores > 1:
+            names = tuple(self.network.actors)
+            cores = {names[i]: c for c, rows in enumerate(part.core_rows)
+                     for i in rows}
+        return decode_trace(self.network, trc, actor_cores=cores)
 
     def collect(self, actor: str, state: Optional[NetworkState] = None) -> Any:
         """Run ``actor``'s ``finish`` hook on its state (paper §3.1);
@@ -1040,29 +1035,34 @@ class Program:
 
         Returns ``{outbound_channel: (total_windows, r, *token_shape)}``.
         """
-        arrays, total, chunk, n_chunks, slab_bytes, ring_bytes = \
-            self._prepare_stream(feeds, on_fault, max_retries, persistent,
-                                 checkpoint_dir, checkpoint_every)
-        report: List[Dict[str, Any]] = []
-        self.last_stream_report = report
-        if persistent:
-            try:
-                return self._stream_persistent(arrays, total, chunk,
-                                               n_chunks, slab_bytes,
-                                               ring_bytes)
-            except NetworkFaultError as err:
-                if on_fault == "raise":
-                    raise
-                # The checkpointed chunk loop is the only surface with
-                # something to restore; re-run the stream there (its
-                # outputs are bit-identical by the concatenation
-                # invariant, so the fallback changes recovery, not data).
-                report.append({"chunk": None, "attempts": 1,
-                               "action": "fallback-chunked",
-                               "fault": str(err)})
-        return self._stream_chunked(arrays, total, chunk, n_chunks, on_fault,
-                                    max_retries, slab_bytes, ring_bytes,
-                                    report, checkpoint_dir, checkpoint_every)
+        with jax.profiler.TraceAnnotation(SPAN_STREAM):
+            with jax.profiler.TraceAnnotation(SPAN_PREPARE):
+                arrays, total, chunk, n_chunks, slab_bytes, ring_bytes = \
+                    self._prepare_stream(feeds, on_fault, max_retries,
+                                         persistent, checkpoint_dir,
+                                         checkpoint_every)
+            report: List[Dict[str, Any]] = []
+            self.last_stream_report = report
+            if persistent:
+                try:
+                    return self._stream_persistent(arrays, total, chunk,
+                                                   n_chunks, slab_bytes,
+                                                   ring_bytes)
+                except NetworkFaultError as err:
+                    if on_fault == "raise":
+                        raise
+                    # The checkpointed chunk loop is the only surface with
+                    # something to restore; re-run the stream there (its
+                    # outputs are bit-identical by the concatenation
+                    # invariant, so the fallback changes recovery, not
+                    # data).
+                    report.append({"chunk": None, "attempts": 1,
+                                   "action": "fallback-chunked",
+                                   "fault": str(err)})
+            return self._stream_chunked(arrays, total, chunk, n_chunks,
+                                        on_fault, max_retries, slab_bytes,
+                                        ring_bytes, report, checkpoint_dir,
+                                        checkpoint_every)
 
     def _prepare_stream(self, feeds: Mapping[str, Any], on_fault: str,
                         max_retries: int, persistent: bool,
@@ -1240,34 +1240,37 @@ class Program:
         # concatenation invariant its single run is bit-identical to
         # the chunked loop, and the feed slabs (sized total instead of
         # chunk) are staged exactly once.
-        prog = self._persistent_progs.get(total)
-        if prog is None:
-            prog = Program(
-                self.source_network,
-                dataclasses.replace(self.plan, n_iterations=total))
-            self._persistent_progs[total] = prog
-        base = prog.init_state()
-        for fifo, arr in arrays.items():
-            base = prog._set_actor(base, prog._feed_by_fifo[fifo],
-                                   (arr, jnp.int32(0)))
+        with jax.profiler.TraceAnnotation(SPAN_STAGE):
+            prog = self._persistent_progs.get(total)
+            if prog is None:
+                prog = Program(
+                    self.source_network,
+                    dataclasses.replace(self.plan, n_iterations=total))
+                self._persistent_progs[total] = prog
+            base = prog.init_state()
+            for fifo, arr in arrays.items():
+                base = prog._set_actor(base, prog._feed_by_fifo[fifo],
+                                       (arr, jnp.int32(0)))
         result = prog.run(base)
-        # collect() stays guarded: the implicit state belongs to the
-        # full-length twin program, not this chunk-length one.
-        self._last = result
-        self._last_is_stream_chunk = True
-        self.last_stream_trace = result.trace
-        self.last_stream_fire_counts = (
-            {k: int(v) for k, v in result.fire_counts.items()}
-            if result.fire_counts is not None else None)
-        self.last_stream_sweeps = (int(result.sweeps)
-                                   if result.sweeps is not None else None)
-        self._last_stream = {
-            "chunks": n_chunks, "persistent": True,
-            "staged_bytes_per_chunk": slab_bytes,
-            "total_staged_bytes": ring_bytes + n_chunks * slab_bytes,
-        }
-        return {f: result.state.actor(prog._fetch_by_fifo[f])[0]
-                for f in self._fetch_by_fifo}
+        with jax.profiler.TraceAnnotation(SPAN_COLLECT):
+            # collect() stays guarded: the implicit state belongs to the
+            # full-length twin program, not this chunk-length one.
+            self._last = result
+            self._last_is_stream_chunk = True
+            self.last_stream_trace = result.trace
+            self.last_stream_fire_counts = (
+                {k: int(v) for k, v in result.fire_counts.items()}
+                if result.fire_counts is not None else None)
+            self.last_stream_sweeps = (int(result.sweeps)
+                                       if result.sweeps is not None
+                                       else None)
+            self._last_stream = {
+                "chunks": n_chunks, "persistent": True,
+                "staged_bytes_per_chunk": slab_bytes,
+                "total_staged_bytes": ring_bytes + n_chunks * slab_bytes,
+            }
+            return {f: result.state.actor(prog._fetch_by_fifo[f])[0]
+                    for f in self._fetch_by_fifo}
 
     def _stream_chunked(self, arrays: Mapping[str, jax.Array], total: int,
                         chunk: int, n_chunks: int, on_fault: str,
@@ -1288,8 +1291,6 @@ class Program:
         intact snapshot, with every accumulator restored — the loop body
         cannot tell the difference, which is the bit-identity argument.
         """
-        if state is None:
-            state = self.init_state()
         if outs is None:
             outs = {f: [] for f in self._fetch_by_fifo}
         chunk_traces: List[Trace] = [] if traces is None else traces
@@ -1299,29 +1300,61 @@ class Program:
         retrying = on_fault in ("resume", "skip")
         for c in range(start_chunk, n_chunks):
             # The per-chunk checkpoint: the last good NetworkState, before
-            # this chunk's feeds are staged.  Restoring it re-runs (or
-            # skips) the chunk with actor/FIFO history intact.
+            # this chunk's feeds are staged (a fresh stream's first stage
+            # makes it).  Restoring it re-runs (or skips) the chunk with
+            # actor/FIFO history intact.
             checkpoint = state
             attempts = 0
             while True:
-                base = checkpoint
-                if retrying and self.plan.donate is True:
-                    # An explicit-donate run consumes its input buffers —
-                    # which the staged state shares with the checkpoint —
-                    # so every retryable attempt donates a private copy.
-                    base = jax.tree.map(jnp.copy, checkpoint)
-                for fifo, arr in arrays.items():
-                    base = self._set_actor(base, self._feed_by_fifo[fifo],
-                                           (arr[c * chunk:(c + 1) * chunk],
-                                            jnp.int32(0)))
-                for fifo, fetch in self._fetch_by_fifo.items():
-                    slab, _ = base.actor(fetch)
-                    base = self._set_actor(base, fetch,
-                                           (jnp.zeros_like(slab),
-                                            jnp.int32(0)))
+                with jax.profiler.TraceAnnotation(SPAN_STAGE):
+                    if checkpoint is None:
+                        checkpoint = self.init_state()
+                    base = checkpoint
+                    if retrying and self.plan.donate is True:
+                        # An explicit-donate run consumes its input
+                        # buffers — which the staged state shares with the
+                        # checkpoint — so every retryable attempt donates
+                        # a private copy.
+                        base = jax.tree.map(jnp.copy, checkpoint)
+                    for fifo, arr in arrays.items():
+                        base = self._set_actor(
+                            base, self._feed_by_fifo[fifo],
+                            (arr[c * chunk:(c + 1) * chunk], jnp.int32(0)))
+                    for fifo, fetch in self._fetch_by_fifo.items():
+                        slab, _ = base.actor(fetch)
+                        base = self._set_actor(base, fetch,
+                                               (jnp.zeros_like(slab),
+                                                jnp.int32(0)))
                 attempts += 1
                 try:
                     chunk_res = self.run(base)
+                    break
+                except NetworkFaultError as err:
+                    # Guard collect(): the implicit last state holds at
+                    # most this chunk's fetch slabs, not the whole stream.
+                    self._last_is_stream_chunk = True
+                    if on_fault == "resume" and attempts <= max_retries:
+                        continue
+                    if on_fault == "skip":
+                        report.append({"chunk": c, "attempts": attempts,
+                                       "action": "skip", "fault": str(err)})
+                        chunk_res = None
+                        break
+                    report.append({"chunk": c, "attempts": attempts,
+                                   "action": "raise", "fault": str(err)})
+                    err.args = (f"Program.stream: chunk {c} of {n_chunks} "
+                                f"failed after {attempts} attempt(s): "
+                                f"{err.args[0]}",)
+                    raise
+            with jax.profiler.TraceAnnotation(SPAN_COLLECT):
+                if chunk_res is None:
+                    # Skipped: the checkpoint stands, the chunk's windows
+                    # are zeros.
+                    state = checkpoint
+                    for fifo, fetch in self._fetch_by_fifo.items():
+                        outs[fifo].append(
+                            jnp.zeros_like(state.actor(fetch)[0]))
+                else:
                     state = chunk_res.state
                     if chunk_res.fire_counts is not None:
                         if acc_counts is None:
@@ -1332,59 +1365,38 @@ class Program:
                         acc_sweeps += int(chunk_res.sweeps)
                     if chunk_res.trace is not None:
                         chunk_traces.append(chunk_res.trace)
-                    # Guard collect() immediately (not after the loop): the
-                    # implicit last state holds only this chunk's fetch
-                    # slabs, not the whole stream — and must stay guarded
-                    # if a later chunk raises.
                     self._last_is_stream_chunk = True
                     if attempts > 1:
                         report.append({"chunk": c, "attempts": attempts,
                                        "action": "recovered", "fault": None})
                     for fifo, fetch in self._fetch_by_fifo.items():
                         outs[fifo].append(state.actor(fetch)[0])
-                    break
-                except NetworkFaultError as err:
-                    self._last_is_stream_chunk = True
-                    if on_fault == "resume" and attempts <= max_retries:
-                        continue
-                    if on_fault == "skip":
-                        report.append({"chunk": c, "attempts": attempts,
-                                       "action": "skip", "fault": str(err)})
-                        state = checkpoint
-                        for fifo, fetch in self._fetch_by_fifo.items():
-                            outs[fifo].append(
-                                jnp.zeros_like(state.actor(fetch)[0]))
-                        break
-                    report.append({"chunk": c, "attempts": attempts,
-                                   "action": "raise", "fault": str(err)})
-                    err.args = (f"Program.stream: chunk {c} of {n_chunks} "
-                                f"failed after {attempts} attempt(s): "
-                                f"{err.args[0]}",)
-                    raise
-            if checkpoint_dir is not None and (
-                    (c + 1) % checkpoint_every == 0 or c + 1 == n_chunks):
-                # Snapshot AFTER the chunk commits: the payload is the
-                # full progress (state, fetched windows, fire counts,
-                # sweeps, trace ring) and the manifest's step is the count
-                # of chunks durably done.  A kill between snapshots loses
-                # at most checkpoint_every chunks of work, never data
-                # integrity (the writer commits by atomic rename).
-                self._save_stream_snapshot(
-                    checkpoint_dir, c + 1, n_chunks, chunk, total, state,
-                    outs, acc_counts, acc_sweeps, chunk_traces)
-        self.last_stream_fire_counts = (dict(acc_counts)
-                                        if acc_counts is not None else None)
-        self.last_stream_sweeps = acc_sweeps
-        self._last_stream = {
-            "chunks": n_chunks, "persistent": False,
-            "staged_bytes_per_chunk": ring_bytes + slab_bytes,
-            "total_staged_bytes": n_chunks * (ring_bytes + slab_bytes),
-        }
-        # One Trace across the whole stream: later chunks' sweep numbers
-        # are offset past the earlier chunks', so per-actor firing counts
-        # and occupancy series read as a single run.
-        self.last_stream_trace = merge_traces(chunk_traces)
-        return {f: jnp.concatenate(ws, axis=0) for f, ws in outs.items()}
+                if checkpoint_dir is not None and (
+                        (c + 1) % checkpoint_every == 0 or c + 1 == n_chunks):
+                    # Snapshot AFTER the chunk commits: the payload is the
+                    # full progress (state, fetched windows, fire counts,
+                    # sweeps, trace ring) and the manifest's step is the
+                    # count of chunks durably done.  A kill between
+                    # snapshots loses at most checkpoint_every chunks of
+                    # work, never data integrity (the writer commits by
+                    # atomic rename).
+                    self._save_stream_snapshot(
+                        checkpoint_dir, c + 1, n_chunks, chunk, total, state,
+                        outs, acc_counts, acc_sweeps, chunk_traces)
+        with jax.profiler.TraceAnnotation(SPAN_COLLECT):
+            self.last_stream_fire_counts = (
+                dict(acc_counts) if acc_counts is not None else None)
+            self.last_stream_sweeps = acc_sweeps
+            self._last_stream = {
+                "chunks": n_chunks, "persistent": False,
+                "staged_bytes_per_chunk": ring_bytes + slab_bytes,
+                "total_staged_bytes": n_chunks * (ring_bytes + slab_bytes),
+            }
+            # One Trace across the whole stream: later chunks' sweep
+            # numbers are offset past the earlier chunks', so per-actor
+            # firing counts and occupancy series read as a single run.
+            self.last_stream_trace = merge_traces(chunk_traces)
+            return {f: jnp.concatenate(ws, axis=0) for f, ws in outs.items()}
 
     # ------------------------------------------------------------------ #
     # Durable snapshots: payload <-> plain-container serialization.
@@ -1486,9 +1498,10 @@ class Program:
         that fail their CRC (torn by the kill) are skipped in favor of
         the next-newest intact one.
         """
-        arrays, total, chunk, n_chunks, slab_bytes, ring_bytes = \
-            self._prepare_stream(feeds, on_fault, max_retries, False,
-                                 checkpoint_dir, checkpoint_every)
+        with jax.profiler.TraceAnnotation(SPAN_PREPARE):
+            arrays, total, chunk, n_chunks, slab_bytes, ring_bytes = \
+                self._prepare_stream(feeds, on_fault, max_retries, False,
+                                     checkpoint_dir, checkpoint_every)
         payload, meta, step = load_stream_checkpoint(checkpoint_dir)
         if meta.get("kind") != "stream":
             raise ValueError(

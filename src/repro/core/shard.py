@@ -296,9 +296,7 @@ def compile_sharded(network: Network, layout: MegakernelLayout,
 
 
 def decode_device_trace(network: Network, trc: Optional[Tuple],
-                        partition: GridPartition,
-                        wall_time_s: Optional[float] = None
-                        ) -> Optional[Trace]:
+                        partition: GridPartition) -> Optional[Trace]:
     """Decode the all-gathered ``(rings (k, cap, 3+F), counts (k,))``
     pair of a sharded run into ONE :class:`repro.core.trace.Trace`:
     per-device rings are decoded independently, then interleaved by
@@ -315,7 +313,6 @@ def decode_device_trace(network: Network, trc: Optional[Tuple],
         decode_trace(network,
                      TraceState(ring=jnp.asarray(rings[d]),
                                 count=jnp.asarray(counts[d])),
-                     wall_time_s=wall_time_s if d == 0 else None,
                      actor_cores=devmap)
         for d in range(partition.n_cores)
     ]

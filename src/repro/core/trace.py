@@ -25,14 +25,19 @@ On host, :func:`decode_trace` unwraps the ring into a :class:`Trace`:
     thread track per actor (grouped per core under grid partitioning),
     an occupancy counter track per channel — viewable in Perfetto
     (https://ui.perfetto.dev) or chrome://tracing;
-  * ``trace.profile()`` derives a :class:`Profile`: per-actor mean
-    firing cost (host wall-clock attributed over firings on the dynamic
-    executor; firing-count x flops weighted in-kernel, where no
-    per-firing clock exists) and per-channel occupancy churn;
+  * ``trace.profile()`` derives a :class:`Profile`: per-actor firing
+    counts and per-channel occupancy churn (the ring has no clock, so
+    it measures counts and order, never time);
   * ``Profile.as_cut_weights()`` feeds
     ``ExecutionPlan(cut_objective="profile", profile=...)`` so the grid
     partition cut uses *measured* churn instead of static capacity
     bytes — the measurement half of the ROADMAP autotuner.
+
+Time comes from the profiler instead: :class:`repro.core.program.Program`
+and :class:`repro.serve.ActorEngine` open host spans
+(``jax.profiler.TraceAnnotation``) named by the ``SPAN_*`` constants
+below, which a profiler trace puts on the same clock as the device's
+operations.  With no profiler running each span costs one inactive check.
 
 Event rows are int32 vectors ``[actor_index, sweep, fired,
 occ_0..occ_{F-1}]`` (width ``3 + n_fifos``); the column offsets are the
@@ -54,6 +59,18 @@ TRACE_CAPACITY_DEFAULT = 4096
 
 # Event-row column layout (int32): [actor, sweep, fired, occ_0..occ_{F-1}].
 COL_ACTOR, COL_SWEEP, COL_FIRED, COL_OCC = 0, 1, 2, 3
+
+# Host spans of the program, in the profiler's trace.  ``Program.run``
+# spans nest in a stream's chunk; ``Program.run/wait`` is the host blocked
+# on the device, every other phase is host work.
+SPAN_STREAM = "Program.stream"              # a whole stream call
+SPAN_PREPARE = "Program.stream/prepare"     # validation, feeds to device
+SPAN_STAGE = "Program.stream/stage"         # a chunk's state and feeds
+SPAN_LAUNCH = "Program.run/launch"          # dispatch (and any compile)
+SPAN_WAIT = "Program.run/wait"              # the run's one host sync
+SPAN_DECODE = "Program.run/decode"          # health and trace-ring decode
+SPAN_COLLECT = "Program.stream/collect"     # counts, outputs, snapshots
+SPAN_BUILD = "ActorEngine.generate/build"   # request staging, net, compile
 
 
 # --------------------------------------------------------------------------- #
@@ -107,8 +124,7 @@ class Trace:
     events: np.ndarray
     capacity: int
     dropped: int = 0
-    wall_time_s: Optional[float] = None
-    #: Static per-actor cost estimates (flops), for wall-clock attribution.
+    #: Static per-actor cost estimates (flops), for the cut weights.
     actor_flops: Tuple[int, ...] = ()
     #: Per-channel token sizes (bytes), for churn-in-bytes profiles.
     fifo_token_bytes: Tuple[int, ...] = ()
@@ -146,37 +162,17 @@ class Trace:
                   if self.n_events else 0)
         ev = other.events.copy()
         ev[:, COL_SWEEP] += offset
-        wall = None
-        if self.wall_time_s is not None or other.wall_time_s is not None:
-            wall = (self.wall_time_s or 0.0) + (other.wall_time_s or 0.0)
         return dataclasses.replace(
             self, events=np.concatenate([self.events, ev], axis=0),
-            dropped=self.dropped + other.dropped, wall_time_s=wall)
+            dropped=self.dropped + other.dropped)
 
     # ------------------------------------------------------------------ #
     def profile(self) -> "Profile":
-        """Derive measured per-actor costs and per-channel churn."""
+        """Derive per-actor firing counts and per-channel churn."""
         firings = self.firing_counts()
-        # Wall-clock attribution: total run wall time split over actors
-        # proportionally to firings x static flops (the dynamic executor
-        # measures one wall clock around the whole jitted run — there is
-        # no per-firing host clock inside a lax.while_loop, and none at
-        # all inside the kernel).  Where no wall time was measured the
-        # cost stays None and as_cut_weights falls back to the same
-        # firings x flops weights.
         flops = {nm: max(1, int(f)) for nm, f in
                  zip(self.actor_names, self.actor_flops or
                      (1,) * len(self.actor_names))}
-        weight = {nm: firings.get(nm, 0) * flops[nm]
-                  for nm in self.actor_names}
-        total_w = sum(weight.values())
-        cost_s: Optional[Dict[str, float]] = None
-        if self.wall_time_s is not None and total_w > 0:
-            cost_s = {}
-            for nm in self.actor_names:
-                n = firings.get(nm, 0)
-                cost_s[nm] = (self.wall_time_s * weight[nm] / total_w / n
-                              if n else 0.0)
         # Occupancy churn: total |delta occ| between consecutive samples,
         # scaled to bytes by the channel token size — a measured stand-in
         # for "traffic through this channel" that a crossing cut wants to
@@ -190,8 +186,7 @@ class Trace:
             churn[nm] = delta * max(1, int(tok_bytes[i]))
         return Profile(actor_names=self.actor_names,
                        firing_counts=firings, actor_flops=flops,
-                       actor_cost_s=cost_s, channel_churn_bytes=churn,
-                       wall_time_s=self.wall_time_s, dropped=self.dropped)
+                       channel_churn_bytes=churn, dropped=self.dropped)
 
     # ------------------------------------------------------------------ #
     def to_perfetto(self, path: Optional[str] = None) -> dict:
@@ -200,13 +195,11 @@ class Trace:
         One thread track per actor — named ``actor [core k]`` under grid
         partitioning — plus one counter track per channel (``occ:name``,
         emitted on change).  Fired attempts are complete ("X") slices,
-        skipped attempts thread-scoped instants ("i").  Event timestamps
-        are event-ordinal microseconds scaled so the timeline spans the
-        measured wall time when one exists.
+        skipped attempts thread-scoped instants ("i").  Timestamps are
+        event ordinals, one microsecond per attempt: the ring records
+        order, not time.
         """
         n = self.n_events
-        scale = (self.wall_time_s * 1e6 / n
-                 if self.wall_time_s and n else 1.0)
         ev: List[dict] = [{"name": "process_name", "ph": "M", "pid": 0,
                            "tid": 0, "args": {"name": "actor network"}}]
         for i, nm in enumerate(self.actor_names):
@@ -218,13 +211,13 @@ class Trace:
         prev_occ: Dict[str, int] = {}
         for k in range(n):
             row = self.events[k]
-            ts = k * scale
+            ts = float(k)
             a = int(row[COL_ACTOR])
             base = {"cat": "firing", "pid": 0, "tid": a + 1, "ts": ts,
                     "args": {"sweep": int(row[COL_SWEEP])}}
             if int(row[COL_FIRED]):
                 ev.append({"name": self.actor_names[a], "ph": "X",
-                           "dur": scale, **base})
+                           "dur": 1.0, **base})
             else:
                 ev.append({"name": f"{self.actor_names[a]} (skipped)",
                            "ph": "i", "s": "t", **base})
@@ -236,8 +229,7 @@ class Trace:
                                "ts": ts, "args": {"tokens": occ}})
         doc = {"traceEvents": ev, "displayTimeUnit": "ms",
                "otherData": {"dropped_events": self.dropped,
-                             "capacity": self.capacity,
-                             "wall_time_s": self.wall_time_s}}
+                             "capacity": self.capacity}}
         if path is not None:
             with open(path, "w") as f:
                 json.dump(doc, f)
@@ -289,16 +281,12 @@ def merge_device_traces(traces: Sequence[Trace]) -> Optional[Trace]:
 # --------------------------------------------------------------------------- #
 @dataclasses.dataclass(frozen=True)
 class Profile:
-    """Measured per-actor cost and per-channel occupancy churn."""
+    """Measured per-actor firings and per-channel occupancy churn."""
 
     actor_names: Tuple[str, ...]
     firing_counts: Dict[str, int]
     actor_flops: Dict[str, int]
-    #: Mean seconds per firing (None when no wall clock was measured —
-    #: the in-kernel case; weights then fall back to firings x flops).
-    actor_cost_s: Optional[Dict[str, float]]
     channel_churn_bytes: Dict[str, int]
-    wall_time_s: Optional[float] = None
     dropped: int = 0
 
     def as_cut_weights(self) -> Dict[str, Dict[str, int]]:
@@ -316,7 +304,6 @@ class Profile:
 # Decode (device pytree -> host Trace).
 # --------------------------------------------------------------------------- #
 def decode_trace(network, trace: Optional[TraceState],
-                 wall_time_s: Optional[float] = None,
                  actor_cores: Optional[Mapping[str, int]] = None
                  ) -> Optional[Trace]:
     """Unwrap a device trace ring into a chronological :class:`Trace`."""
@@ -341,7 +328,7 @@ def decode_trace(network, trace: Optional[TraceState],
         cores = tuple(int(actor_cores.get(nm, 0)) for nm in actor_names)
     return Trace(actor_names=actor_names, fifo_names=fifo_names,
                  events=events, capacity=cap,
-                 dropped=max(0, total - cap), wall_time_s=wall_time_s,
+                 dropped=max(0, total - cap),
                  actor_flops=flops, fifo_token_bytes=tok_bytes,
                  actor_cores=cores)
 

@@ -39,11 +39,13 @@ from __future__ import annotations
 
 from typing import Any, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core import ExecutionPlan
 from repro.core.health import NetworkFaultError
+from repro.core.trace import SPAN_BUILD
 from repro.graphs.serving import (STATUS_FAULT, STATUS_OK, STATUS_SHED,
                                   STATUS_TIMEOUT, ServingWorkload,
                                   build_serving_network, faulted_requests,
@@ -166,8 +168,9 @@ class ActorEngine:
                 idxs = [i for i, _ in cur]
                 arr = None if arr_all is None else arr_all[idxs]
                 dl = None if dl_all is None else dl_all[idxs]
-                wl, net = self._stage([r for _, r in cur], arr, dl)
-                prog = net.compile(self.plan)
+                with jax.profiler.TraceAnnotation(SPAN_BUILD):
+                    wl, net = self._stage([r for _, r in cur], arr, dl)
+                    prog = net.compile(self.plan)
                 try:
                     res = prog.run()
                     break

@@ -353,3 +353,20 @@ def test_stats_schema_v2_superset_of_v1(dpd):
     assert doc["collective_bytes_per_sweep"] is None
     assert doc["quiescence_allreduces"] is None
     assert json.loads(json.dumps(doc)) == doc
+
+
+@pytest.mark.parametrize("old_payload", [False, True])
+def test_trace_snapshot_payload_round_trips(dpd, old_payload):
+    """Snapshot payloads carry no wall clock; an older payload that does
+    still reads back (the entry is ignored)."""
+    from repro.core.program import _trace_from_payload, _trace_to_payload
+    t = dpd.compile(_plan("dynamic", trace=True)).run().trace
+    payload = _trace_to_payload(t)
+    assert "wall_time_s" not in payload
+    if old_payload:
+        payload["wall_time_s"] = 0.25
+    back = _trace_from_payload(payload)
+    np.testing.assert_array_equal(back.events, t.events)
+    assert dataclasses.replace(back, events=t.events) == dataclasses.replace(
+        t, events=t.events)
+    assert back.profile().as_cut_weights() == t.profile().as_cut_weights()
