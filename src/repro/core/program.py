@@ -823,19 +823,22 @@ class Program:
         under a heterogeneous plan)."""
         return self.network.init_state()
 
-    def run(self, state: Optional[Any] = None) -> RunResult:
+    def run(self, state: Optional[Any] = None,
+            private: bool = False) -> RunResult:
         """Execute once from ``state`` (fresh :meth:`init_state` if None).
 
         Legacy ``{"fifos": ..., "actors": ...}`` dict states are accepted.
         With an explicit ``plan.donate=True`` a passed-in state's buffers
         are consumed; under the default ``"auto"`` only runs that create
         their own state donate (a private copy), so caller-held arrays
-        stay valid.
+        stay valid.  ``private=True`` declares a passed-in state the
+        caller's own, no array of it held anywhere else: it is donated
+        as that private copy would be, without the copy.
         """
         st = self.init_state() if state is None else state
-        if state is None:
+        if state is None or private:
             donate_now = self.donate
-            if donate_now:
+            if donate_now and state is None:
                 # init_state() may alias arrays staged in the graph closure
                 # (e.g. a source's signal slab); donating those would
                 # poison every later init_state() of the network.  When

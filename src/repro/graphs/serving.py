@@ -188,6 +188,41 @@ def poisson_trace(n: int, rate: float, seed: int = 0) -> np.ndarray:
     return np.floor(np.cumsum(gaps)).astype(np.int32)
 
 
+def admission_state(workload: ServingWorkload, *, max_prompt: int,
+                    max_new: int) -> Dict[str, jax.Array]:
+    """The admission actor's initial state for ``workload``: the staged
+    request slabs, which every admission firing reads and passes through
+    unchanged, and zeroed queue counters.
+
+    Validates the workload first.  The arrays are new on every call and
+    shared with nothing, so a run may donate them; a program built for one
+    workload serves another of the same request count from this state."""
+    R, P, N = int(workload.prompts.shape[0]), max_prompt, max_new
+    if R == 0:
+        raise ValueError("serving: empty workload; stage >= 1 request")
+    if workload.prompts.shape[1] != P:
+        raise ValueError(
+            f"serving: prompt slab width {workload.prompts.shape[1]} != "
+            f"max_prompt {P}")
+    if (workload.budgets < 1).any() or (workload.budgets > N).any():
+        raise ValueError(
+            f"serving: per-request budgets must be in 1..max_new={N}")
+    if (np.diff(workload.arrivals) < 0).any():
+        raise ValueError("serving: arrival trace must be ascending")
+    deadlines = (np.full((R,), NO_DEADLINE, np.int32)
+                 if workload.deadlines is None
+                 else np.asarray(workload.deadlines, np.int32))
+    if deadlines.shape != (R,):
+        raise ValueError(
+            f"serving: deadlines shape {deadlines.shape} != ({R},)")
+    return {"taken": jnp.zeros((R,), jnp.int32), "t": jnp.int32(0),
+            "retired": jnp.int32(0),
+            "prompts": jnp.array(workload.prompts, jnp.int32),
+            "budgets": jnp.array(workload.budgets, jnp.int32),
+            "arrivals": jnp.array(workload.arrivals, jnp.int32),
+            "deadlines": jnp.array(deadlines, jnp.int32)}
+
+
 # --------------------------------------------------------------------- #
 # Graph construction.
 # --------------------------------------------------------------------- #
@@ -200,7 +235,8 @@ def build_serving_network(cfg: ArchConfig, params: PyTree,
                           check_bounds: bool = True,
                           return_bounds: bool = False) -> Network:
     """Build the admission/gate/decode/merge/retire serving network with
-    ``workload`` staged as the host-fed arrival queue.
+    ``workload`` staged as the host-fed arrival queue, in the admission
+    actor's initial state (``admission_state``).
 
     ``queue_depth`` bounds the waiting queue: arrived requests that would
     queue deeper than ``queue_depth`` behind this firing's admissions are
@@ -213,43 +249,26 @@ def build_serving_network(cfg: ArchConfig, params: PyTree,
     can pin the per-channel verdicts the build proved."""
     B, P, N = batch_size, max_prompt, max_new
     W = HEADER + P + N
+    admission_state(workload, max_prompt=P, max_new=N)    # validates
     R = int(workload.prompts.shape[0])
-    if R == 0:
-        raise ValueError("serving: empty workload; stage >= 1 request")
-    if workload.prompts.shape[1] != P:
-        raise ValueError(
-            f"serving: prompt slab width {workload.prompts.shape[1]} != "
-            f"max_prompt {P}")
-    if (workload.budgets < 1).any() or (workload.budgets > N).any():
-        raise ValueError(
-            f"serving: per-request budgets must be in 1..max_new={N}")
-    if (np.diff(workload.arrivals) < 0).any():
-        raise ValueError("serving: arrival trace must be ascending")
     if queue_depth is not None and queue_depth < 0:
         raise ValueError(f"serving: queue_depth={queue_depth} must be >= 0")
-    deadlines_np = (np.full((R,), NO_DEADLINE, np.int32)
-                    if workload.deadlines is None
-                    else np.asarray(workload.deadlines, np.int32))
-    if deadlines_np.shape != (R,):
-        raise ValueError(
-            f"serving: deadlines shape {deadlines_np.shape} != ({R},)")
     eos = jnp.int32(-1 if eos_id is None else eos_id)
     cache_len = P + N
-
-    prompts = jnp.asarray(workload.prompts, jnp.int32)
-    budgets = jnp.asarray(workload.budgets, jnp.int32)
-    arrivals = jnp.asarray(workload.arrivals, jnp.int32)
-    deadlines = jnp.asarray(deadlines_np, jnp.int32)
     qd = jnp.int32(B + R if queue_depth is None else queue_depth)
 
     # -- admission: static loop head -------------------------------------
+    # The staged requests ride the actor's state, not this closure: the
+    # network depends on the workload only through R, so its program
+    # serves any request set of R requests (ActorEngine keeps one per R).
     def admission_init():
-        return {"taken": jnp.zeros((R,), jnp.int32), "t": jnp.int32(0),
-                "retired": jnp.int32(0)}
+        return admission_state(workload, max_prompt=P, max_new=N)
 
     def admission_fire(st, ins, rates):
         del rates
         t = st["t"]
+        prompts, budgets = st["prompts"], st["budgets"]
+        arrivals, deadlines = st["arrivals"], st["deadlines"]
         idx = jnp.arange(R, dtype=jnp.int32)
         tbl = ins["fb"][0]
         # In-flight deadline expiry retires the slot exactly like an EOS:
@@ -352,7 +371,7 @@ def build_serving_network(cfg: ArchConfig, params: PyTree,
         # equal and mark the xa/y/fina channels matched.
         ctl = jnp.stack([n_active, n_out, k])
         taken = jnp.where(admit_req | emit, 1, st["taken"])
-        st = {"taken": taken, "t": t + 1,
+        st = {**st, "taken": taken, "t": t + 1,
               "retired": st["retired"] + n_out}
         return st, {"table": tbl, "x": tbl, "fin": fin_rows,
                     "c_gate": ctl, "c_dec": ctl, "c_merge": ctl,

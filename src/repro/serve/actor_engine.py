@@ -34,9 +34,17 @@ the survivors from the pre-run checkpoint (the initial state — one
 bit-identical to a fault-free run of the same survivor set: admission
 timing cannot change a dense request's tokens (the module-level
 bit-identity contract).
+
+Programs: the serving network depends on its requests only through
+their number R (the request slabs ride the admission actor's state), so
+the engine builds and compiles one program per live request count and
+serves every later request set of that count from it, the set staged as
+the run's initial state.  It keeps the ``_MAX_PROGRAMS`` most recently
+used; ``programs_built`` and ``last_program_reused`` count the hits.
 """
 from __future__ import annotations
 
+from collections import OrderedDict
 from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
@@ -48,14 +56,18 @@ from repro.core.health import NetworkFaultError
 from repro.core.trace import SPAN_BUILD
 from repro.graphs.serving import (STATUS_FAULT, STATUS_OK, STATUS_SHED,
                                   STATUS_TIMEOUT, ServingWorkload,
-                                  build_serving_network, faulted_requests,
-                                  left_pad_prompts)
+                                  admission_state, build_serving_network,
+                                  faulted_requests, left_pad_prompts)
 from repro.serve.engine import Request, Result, ServeConfig
 
 PyTree = Any
 
 _STATUS_STR = {STATUS_OK: "ok", STATUS_TIMEOUT: "timeout",
                STATUS_SHED: "shed", STATUS_FAULT: "fault"}
+
+#: Compiled serving programs an engine keeps, one per live request count;
+#: the least recently used is dropped first.
+_MAX_PROGRAMS = 4
 
 
 class ActorEngine:
@@ -93,12 +105,19 @@ class ActorEngine:
         #: the plan says devices > 1): bytes each sweep-barrier exchange
         #: moves across the mesh, from Program.stats().
         self.last_collective_bytes_per_sweep: Optional[int] = None
+        #: Serving programs built so far (one per live request count not
+        #: kept from an earlier call), and whether the last attempt of the
+        #: last generate() call ran a program kept from an earlier one.
+        self.programs_built: int = 0
+        self.last_program_reused: Optional[bool] = None
+        #: (network, program) per request count R, least recently used
+        #: first.
+        self._programs: "OrderedDict[int, Tuple[Any, Any]]" = OrderedDict()
 
     # ------------------------------------------------------------------ #
-    def _stage(self, requests: Sequence[Request],
-               arrivals: Optional[np.ndarray],
-               deadlines: Optional[np.ndarray]
-               ) -> Tuple[ServingWorkload, Any]:
+    def _workload(self, requests: Sequence[Request],
+                  arrivals: Optional[np.ndarray],
+                  deadlines: Optional[np.ndarray]) -> ServingWorkload:
         scfg = self.scfg
         slab, lens = left_pad_prompts([r.prompt for r in requests],
                                       scfg.max_prompt)
@@ -116,22 +135,45 @@ class ActorEngine:
             raise ValueError(
                 f"ActorEngine: deadlines shape {dl.shape} != "
                 f"({len(requests)},)")
-        wl = ServingWorkload(prompts=slab, prompt_lens=lens,
-                             budgets=budgets, arrivals=arrivals,
-                             deadlines=dl)
-        net = build_serving_network(
+        return ServingWorkload(prompts=slab, prompt_lens=lens,
+                               budgets=budgets, arrivals=arrivals,
+                               deadlines=dl)
+
+    def _network(self, wl: ServingWorkload):
+        scfg = self.scfg
+        return build_serving_network(
             self.cfg, self.params, wl, batch_size=scfg.batch_size,
             max_prompt=scfg.max_prompt, max_new=scfg.max_new,
             eos_id=scfg.eos_id, kernel_impl=scfg.kernel_impl,
             queue_depth=self.queue_depth)
-        return wl, net
+
+    def _staged(self, wl: ServingWorkload):
+        """``(network, program, state)`` serving ``wl``: the program kept
+        for its request count, else a new one, and its initial state with
+        ``wl`` in the admission actor."""
+        R = int(wl.prompts.shape[0])
+        kept = self._programs.pop(R, None)
+        self.last_program_reused = kept is not None
+        if kept is None:
+            net = self._network(wl)
+            kept = (net, net.compile(self.plan))
+            self.programs_built += 1
+            if len(self._programs) >= _MAX_PROGRAMS:
+                self._programs.popitem(last=False)
+        self._programs[R] = kept
+        net, prog = kept
+        adm = admission_state(wl, max_prompt=self.scfg.max_prompt,
+                              max_new=self.scfg.max_new)
+        st = prog.init_state().replace_actor(
+            prog.network.actor_index["admission"], adm)
+        return net, prog, st
 
     def build_network(self, requests: Sequence[Request],
                       arrivals: Optional[np.ndarray] = None,
                       deadlines: Optional[np.ndarray] = None):
         """The serving network with these requests staged (exposed for
         tests/benchmarks that inspect the graph or pick their own plan)."""
-        return self._stage(requests, arrivals, deadlines)[1]
+        return self._network(self._workload(requests, arrivals, deadlines))
 
     def generate(self, requests: List[Request],
                  arrivals: Optional[np.ndarray] = None,
@@ -162,17 +204,20 @@ class ActorEngine:
             # Quarantine loop: every retry re-runs the survivor set from
             # the pre-run checkpoint (the initial network state) with the
             # culprits excluded; each round excludes >= 1 request, so the
-            # loop is bounded by min(max_retries, len(live)).
+            # loop is bounded by min(max_retries, len(live)).  A survivor
+            # set is a smaller R: another program, kept or new.
             cur = list(live)
             while True:
                 idxs = [i for i, _ in cur]
                 arr = None if arr_all is None else arr_all[idxs]
                 dl = None if dl_all is None else dl_all[idxs]
                 with jax.profiler.TraceAnnotation(SPAN_BUILD):
-                    wl, net = self._stage([r for _, r in cur], arr, dl)
-                    prog = net.compile(self.plan)
+                    wl = self._workload([r for _, r in cur], arr, dl)
+                    net, prog, st = self._staged(wl)
                 try:
-                    res = prog.run()
+                    # The state is this call's own: donated as run(None)
+                    # donates its private copy.
+                    res = prog.run(st, private=True)
                     break
                 except NetworkFaultError as err:
                     if on_fault != "quarantine":

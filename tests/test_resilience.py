@@ -107,6 +107,28 @@ def test_poison_quarantined_survivors_bit_identical(lm, reqs, scfg,
         assert out[i].tokens.tolist() == baseline[i], i
 
 
+def test_quarantine_retry_on_a_kept_program(lm, reqs, scfg, baseline):
+    """The survivor set's retry runs on the program an earlier call of the
+    same request count built, and its tokens equal the fault-free run's."""
+    cfg, params = lm
+    eng = ActorEngine(cfg, params, scfg,
+                      plan=ExecutionPlan(mode="dynamic", guards=True))
+    rng = np.random.default_rng(8)
+    earlier = [Request(prompt=rng.integers(1, cfg.vocab, size=5)
+                       .astype(np.int32), max_new=3) for _ in range(4)]
+    eng.generate(earlier)                       # builds the program for 4
+    bad = list(reqs)
+    bad[1] = Request(prompt=np.full(4, POISON_VALUE, np.int32), max_new=4)
+    out = eng.generate(bad, on_fault="quarantine")
+    assert eng.last_retries == 1
+    assert eng.last_program_reused              # the survivors: 4 again
+    assert eng.programs_built == 2              # 4, then 5
+    assert eng.last_status[1] == "fault"
+    for i in (0, 2, 3, 4):
+        assert eng.last_status[i] == "ok"
+        assert out[i].tokens.tolist() == baseline[i], i
+
+
 @pytest.mark.parametrize("mode", ("dynamic", "megakernel"))
 def test_expired_deadline_sheds_as_timeout(lm, reqs, scfg, baseline, mode):
     cfg, params = lm
@@ -156,7 +178,7 @@ def test_mid_flight_deadline_keeps_token_prefix(lm, reqs, scfg, baseline):
 def test_injector_validation(lm, reqs, scfg):
     cfg, params = lm
     eng = ActorEngine(cfg, params, scfg)
-    wl, _ = eng._stage(reqs, None, None)
+    wl = eng._workload(reqs, None, None)
     with pytest.raises(ValueError, match="out of range"):
         poison_request(wl, 99)
     with pytest.raises(ValueError, match="not a poison"):

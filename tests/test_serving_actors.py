@@ -1,5 +1,7 @@
 """Continuous-batching serving actors: identity vs the legacy Engine,
 rate-0 idle firings, re-admission, declared-bound verdicts, early stop."""
+import contextlib
+
 import jax
 import numpy as np
 import pytest
@@ -10,6 +12,7 @@ from repro.graphs.serving import (ServingWorkload, build_serving_network,
                                   left_pad_prompts, poisson_trace)
 from repro.models import init_params
 from repro.serve import ActorEngine, Engine, Request, ServeConfig
+from repro.serve import actor_engine
 
 
 @pytest.fixture(scope="module")
@@ -111,6 +114,87 @@ def test_no_request_starves_under_bursty_arrivals(lm, scfg):
     for r, res in zip(reqs, got):
         assert 1 <= len(res.tokens) <= r.max_new
     assert (eng.last_latency_steps >= 1).all()
+
+
+# --------------------------------------------------------------------------- #
+# One program per live request count: later sets of that count are staged
+# into the kept program's state, with no compile.
+# --------------------------------------------------------------------------- #
+def _request_set(cfg, seed, n, idle=()):
+    """``n`` requests with fresh prompts, budgets and arrivals; those at
+    the indices in ``idle`` ask for no tokens (they never reach the
+    network)."""
+    rng = np.random.default_rng(seed)
+    reqs = [Request(prompt=rng.integers(1, cfg.vocab,
+                                        size=int(rng.integers(2, 8)))
+                    .astype(np.int32),
+                    max_new=0 if i in idle else int(rng.integers(1, 5)))
+            for i in range(n)]
+    return reqs, np.sort(rng.integers(0, 4, n)).astype(np.int32)
+
+
+@contextlib.contextmanager
+def _backend_compiles():
+    """Counts JAX's backend compiles while open."""
+    seen = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
+@pytest.mark.parametrize("calls", [
+    # (seed, requests, indices asking for no tokens) per generate call
+    [(11, 3, ()), (12, 3, ())],
+    [(11, 3, ()), (13, 4, ()), (14, 3, ())],
+    [(11, 3, ()), (15, 5, (0, 3))],
+], ids=["same-count", "new-count-then-back", "zero-budget-requests"])
+def test_program_kept_per_live_request_count(lm, scfg, calls):
+    """A set whose live request count the engine has served runs on the
+    kept program with no compile, and its tokens equal a fresh engine's;
+    a new count builds one more program."""
+    cfg, params = lm
+    eng = ActorEngine(cfg, params, scfg)
+    seen_counts = set()
+    for seed, n, idle in calls:
+        reqs, arrivals = _request_set(cfg, seed, n, idle)
+        live = n - len(idle)
+        reused = live in seen_counts
+        seen_counts.add(live)
+        with _backend_compiles() as compiles:
+            got = eng.generate(reqs, arrivals=arrivals)
+        assert eng.last_program_reused is reused
+        assert eng.programs_built == len(seen_counts)
+        if reused:
+            assert compiles == []
+        want = ActorEngine(cfg, params, scfg).generate(reqs,
+                                                       arrivals=arrivals)
+        for w, g in zip(want, got):
+            np.testing.assert_array_equal(w.tokens, g.tokens)
+            assert w.status == g.status
+        for i in idle:
+            assert got[i].tokens.size == 0
+
+
+def test_program_cache_drops_least_recently_used(lm, scfg):
+    cfg, params = lm
+    eng = ActorEngine(cfg, params, scfg)
+    counts = list(range(1, actor_engine._MAX_PROGRAMS + 2))
+    for n in counts:
+        eng.generate(_request_set(cfg, n, n)[0])
+    assert eng.programs_built == len(counts)
+    assert list(eng._programs) == counts[1:]
+    eng.generate(_request_set(cfg, 20, counts[1])[0])
+    assert eng.last_program_reused
+    eng.generate(_request_set(cfg, 21, counts[0])[0])
+    assert not eng.last_program_reused
+    assert eng.programs_built == len(counts) + 1
 
 
 # --------------------------------------------------------------------------- #
