@@ -6,13 +6,15 @@ abstract layout), builds the engine, and generates one warm-up request
 set, compiled in full.  Every call of the window generates a fresh request
 set: the mix's fixed sizes and open-loop arrivals, with prompt tokens
 drawn from the seed and the call's index, so no set repeats in a run.
-``generate`` compiles each request set into its program, so each call
-pays that compile, as a user sending new requests does; the harness keeps
-the window's compiles out of the persistent cache.  The check runs the
-plain float32 reference over a seeded sample of the window's finished
-requests (the longest among them), prompt and served tokens, and reads by
-how much each served token's reference logit lies below the reference's
-best at that position.
+The engine keeps one compiled program per live request count, so the
+warm-up compiles the program that every call of the window uses, and each
+call stages its requests into it; a call with a request count that the
+engine keeps no program for would compile in the window, in full, since
+the harness keeps the window's compiles out of the persistent cache.  The
+check runs the plain float32 reference over a seeded sample of the
+window's finished requests (the longest among them), prompt and served
+tokens, and reads by how much each served token's reference logit lies
+below the reference's best at that position.
 """
 from __future__ import annotations
 
@@ -25,6 +27,46 @@ from bench.lib import lm_ref, traffic
 
 #: Finished requests of the window compared with the reference.
 CHECKED_REQUESTS = 12
+#: ``check`` arguments that put the control in the program's place: the
+#: reference's linear layers in fp8, one precision below the bfloat16
+#: weights.
+CONTROL = {"quant": "fp8"}
+# The smoke LM keeps the full model's depth and logit scale (init std x
+# sqrt(width) = 0.02 x sqrt(3840)): the comparison's readings grow with
+# depth, so at the published 24 layers the program, its control and a
+# wrong token read about as they do at full width.
+LM_SMOKE = dict(num_hidden_layers=24, hidden_size=64, intermediate_size=128,
+                num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+                vocab_size=503, initializer_range=0.155)
+
+
+def smoke(c: dict, mix: dict):
+    """CPU test sizes: the smoke LM, 4 slots, 6 requests of short prompts
+    and answers (a fixed prompt length fills the prompt window, as at full
+    size)."""
+    c = dict(c, **LM_SMOKE)
+    c["serve"] = dict(c["serve"], batch_size=4, max_prompt=16, max_new=8)
+    p = mix["prompt_len"]
+    prompt = (dict(median=16, sigma=0.0, lo=16, hi=16) if p["lo"] == p["hi"]
+              else dict(median=8, sigma=p["sigma"], lo=3, hi=16))
+    return c, dict(mix, requests_per_call=6, prompt_len=prompt,
+                   budget=dict(median=4, sigma=0.7, lo=2, hi=8))
+
+
+def corrupt(monkeypatch):
+    """Alter the last token of every served request where the engine
+    produces it."""
+    from repro.serve import ActorEngine
+    real = ActorEngine.generate
+
+    def generate(self, requests, **kw):
+        out = real(self, requests, **kw)
+        for r in out:
+            r.tokens = r.tokens.copy()
+            r.tokens[-1] = (r.tokens[-1] + self.cfg.vocab // 2) % self.cfg.vocab
+        return out
+
+    monkeypatch.setattr(ActorEngine, "generate", generate)
 
 
 def arch_config(c: dict):

@@ -11,14 +11,26 @@ metric sits in a file of its own, found by name:
 * ``bench/metrics/<metric>.py``: ``read(obs)`` returns the per-layer
   metric from a traced window, or None where it finds nothing to read.
 
-A driver module defines ``Session(cell, seed)``, whose construction is the
-set-up (weights, build, warm-up of every shape the window uses), with
-``call()`` (one unit of the window's work, the same for every seed,
-returning a record once its outputs are on the host),
-``end_to_end(calls, window_s)``,
-``attempted_failed(calls)``, ``observe(calls)`` (counts for the per-layer
-readers), ``release()`` (drops the program's state) and ``check(calls)``
-(the comparison with the plain reference, as ``[(name, value, limit)]``).
+A driver module (``bench/drivers/<driver>.py``, named by the
+configuration's ``driver``) defines:
+
+* ``Session(cell, seed)``, whose construction is the set-up (weights,
+  build, warm-up of every shape the window uses), with ``call()`` (one
+  unit of the window's work, the same for every seed, returning a record
+  once its outputs are on the host), ``end_to_end(calls, window_s)``,
+  ``attempted_failed(calls)``, ``observe(calls)`` (counts for the
+  per-layer readers), ``release()`` (drops the program's state) and
+  ``check(calls, **control)`` (the comparison with the plain reference,
+  as ``[(name, value, limit)]``);
+* ``CONTROL``: the keyword arguments of ``check`` that put the control
+  (the reference one precision lower) in the program's place;
+* ``smoke(config, mix)``: the (config, mix) cut to sizes a CPU test run
+  holds;
+* ``corrupt(monkeypatch)``: alters the answer where the program produces
+  it, so that a run's check must fail.
+
+A driver may find further pieces by name in the configuration, as the
+stream driver finds its graph adapter in ``bench/graphs/<graph>.py``.
 """
 from __future__ import annotations
 

@@ -4,7 +4,8 @@ A mix is ``bench/traffic/<name>.json``; its ``kind`` says which of the
 generators below reads it.  Every size and rate lives in the file, so a new
 mix is a new file.  The generators copy the program's own (the DPD signal of
 ``chip_smoke.py`` and ``repro.graphs.serving.poisson_trace``) so that no
-later change to the program moves the yardstick.
+later change to the program moves the yardstick; the video clips are the
+benchmark's own.
 """
 from __future__ import annotations
 
@@ -62,6 +63,53 @@ def dpd_schedule(mix: dict, n_windows: int, windows_per_period: int
         raise ValueError(f"mix gives {len(per)} periods, call needs "
                          f"{n_periods}")
     return np.repeat(per[:n_periods], windows_per_period)[:n_windows]
+
+
+# --------------------------------------------------------------------- #
+# Video clips (kind "md_frames").
+# --------------------------------------------------------------------- #
+def _bounce(p0: float, v: float, n: int, room: int) -> np.ndarray:
+    """Positions ``p0 + v t`` for ``t < n``, reflected at ``0`` and
+    ``room``: an object that bounces off the frame's edges."""
+    if room <= 0:
+        return np.zeros(n, np.int64)
+    q = np.mod(p0 + v * np.arange(n), 2 * room)
+    return np.rint(np.where(q > room, 2 * room - q, q)).astype(np.int64)
+
+
+def md_clip(rng: np.random.Generator, mix: dict, n_frames: int, h: int,
+            w: int) -> np.ndarray:
+    """(n_frames, h, w) u8 frames of a fixed camera: a smooth background
+    (a level plus a few low-frequency plane waves), the mix's rectangles
+    moving at constant speed and bouncing off the edges, each brighter or
+    darker than what it covers by its contrast, and Gaussian sensor noise
+    on every pixel of every frame."""
+    bg_spec, ob, sigma = mix["background"], mix["objects"], mix["noise_sigma"]
+    y = (np.arange(h, dtype=np.float32) / h)[:, None]
+    x = (np.arange(w, dtype=np.float32) / w)[None, :]
+    bg = np.full((h, w), bg_spec["level"], np.float32)
+    for _ in range(bg_spec["waves"]):
+        fy, fx = rng.uniform(-bg_spec["max_cycles"], bg_spec["max_cycles"],
+                             2)
+        phase = rng.uniform(0, 2 * np.pi)
+        bg += bg_spec["amplitude"] / bg_spec["waves"] * np.cos(
+            2 * np.pi * (fy * y + fx * x) + phase).astype(np.float32)
+    frames = np.repeat(bg[None], n_frames, axis=0)
+    lo, hi = ob["size_frac"]
+    for _ in range(ob["count"]):
+        oh = max(1, int(round(rng.uniform(lo, hi) * h)))
+        ow = max(1, int(round(rng.uniform(lo, hi) * w)))
+        contrast = rng.choice([-1.0, 1.0]) * rng.uniform(*ob["contrast"])
+        speed = rng.uniform(*ob["speed_px"])
+        angle = rng.uniform(0, 2 * np.pi)
+        ys = _bounce(rng.uniform(0, h - oh), speed * np.sin(angle),
+                     n_frames, h - oh)
+        xs = _bounce(rng.uniform(0, w - ow), speed * np.cos(angle),
+                     n_frames, w - ow)
+        for t in range(n_frames):
+            frames[t, ys[t]:ys[t] + oh, xs[t]:xs[t] + ow] += contrast
+    frames += sigma * rng.standard_normal(frames.shape, dtype=np.float32)
+    return np.clip(np.rint(frames), 0, 255).astype(np.uint8)
 
 
 # --------------------------------------------------------------------- #

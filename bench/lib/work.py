@@ -86,6 +86,45 @@ def fir_call(block_l: int, schedule: np.ndarray, n_taps: int = 10):
 
 
 # --------------------------------------------------------------------- #
+# Motion detection (paper section 4.1), at the u8 token contract.
+# --------------------------------------------------------------------- #
+U8_BYTES = 1
+
+
+def gauss_frame(h: int, w: int):
+    """One frame through the Gauss actor: (flops, bytes).  The separable
+    binomial filter's 5 + 5 taps, a multiply and an add each, on every
+    pixel off the 2-pixel border (which passes through); the frame read
+    and written once, a byte a pixel, whatever the implementation holds
+    inside."""
+    return 20 * max(h - 4, 0) * max(w - 4, 0), 2 * U8_BYTES * h * w
+
+
+def md_frame(h: int, w: int):
+    """One frame through the whole network: (flops, bytes).  Gauss as
+    ``gauss_frame``; Thres a difference, its magnitude and a comparison
+    (3) a pixel; Med the 6 comparisons that a median of 5 needs at least.
+    Each actor reads its inputs and writes its outputs once: the feed
+    writes the frame, Gauss reads it and writes its two outputs (direct
+    and delayed), Thres reads both and writes one, Med reads that and
+    writes one, the fetch reads it: ten frames of bytes."""
+    g_flops, _ = gauss_frame(h, w)
+    return g_flops + (3 + 6) * h * w, 10 * U8_BYTES * h * w
+
+
+def md_call(h: int, w: int, n_frames: int):
+    """(flops, bytes) of one call of ``n_frames`` frames."""
+    f, b = md_frame(h, w)
+    return n_frames * f, n_frames * b
+
+
+def gauss_call(h: int, w: int, n_frames: int):
+    """(flops, bytes) of the Gauss kernel launches of one call."""
+    f, b = gauss_frame(h, w)
+    return n_frames * f, n_frames * b
+
+
+# --------------------------------------------------------------------- #
 # Decoder-only LM forward (Llama-style, grouped-query attention).
 # --------------------------------------------------------------------- #
 def lm_matmul_flops_per_token(c: dict) -> int:

@@ -4,8 +4,9 @@ chip: for each seed, one process runs the cell's set-up, a short window at
 its own load (compiles in it bypass the persistent cache, as in a run of
 the benchmark), and then reads the compared
 number twice, for the program and for the control (the reference one
-precision lower in the program's place: bfloat16 for float32 DPD, fp8 for
-the bfloat16 LM).  One JSON line per seed.
+precision lower in the program's place, as the cell's driver's ``CONTROL``
+says: bfloat16 for a float32 streamed graph, fp8 for the bfloat16 LM).
+One JSON line per seed.
 
     python3 bench/limits.py --workload dpd.reconf --seconds 2 --seeds 1 2 3
 """
@@ -19,13 +20,6 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
-
-import ml_dtypes  # noqa: E402
-
-#: Per driver, the ``check`` arguments that put the control in the
-#: program's place.
-CONTROL = {"stream": {"oracle_dtype": ml_dtypes.bfloat16},
-           "serve": {"quant": "fp8"}}
 
 
 def main(argv=None) -> int:
@@ -41,8 +35,7 @@ def main(argv=None) -> int:
     except harness.NoChip as e:
         print(f"limits: {e}", file=sys.stderr)
         return 1
-    driver_name = cell.config["driver"]
-    driver = harness.load_module("drivers", driver_name)
+    driver = harness.load_module("drivers", cell.config["driver"])
     for seed in args.seeds:
         harness.enable_compile_cache()
         sess = driver.Session(cell, seed)
@@ -51,7 +44,7 @@ def main(argv=None) -> int:
         sess.release()
         gc.collect()
         (name, program, limit), = sess.check(calls)
-        (_, control, _), = sess.check(calls, **CONTROL[driver_name])
+        (_, control, _), = sess.check(calls, **driver.CONTROL)
         print(json.dumps({"workload": cell.name, "seed": seed,
                           "calls": len(calls), "number": name,
                           "program": program, "control": control,

@@ -38,6 +38,16 @@ def test_lm_request_flops():
     assert work.lm_request_flops(c, 5, 3) == 7 * per_token + 28 * 4 * 2 * 2 * 3
 
 
+def test_md_counts():
+    # 8x10 frame: 4x6 pixels off the border, 20 flops each, in Gauss;
+    # 3 (Thres) + 6 (Med) flops on every pixel; a frame read and written
+    # by Gauss, ten frames of u8 bytes through the whole network.
+    assert work.gauss_frame(8, 10) == (20 * 4 * 6, 2 * 80)
+    assert work.md_frame(8, 10) == (20 * 4 * 6 + 9 * 80, 10 * 80)
+    assert work.md_call(8, 10, 3) == (3 * (480 + 720), 3 * 800)
+    assert work.gauss_call(8, 10, 3) == (3 * 480, 3 * 160)
+
+
 def test_unknown_device_is_an_error():
     assert work.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
